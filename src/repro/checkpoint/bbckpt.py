@@ -43,6 +43,10 @@ from repro.checkpoint import serializer as ser
 from repro.core import telemetry
 from repro.core.system import BurstBufferSystem
 
+# The slowest flush rate waited for before a flush counts as not
+# acknowledged: a deadline that grows with the checkpoint's bytes.
+FLUSH_FLOOR_BYTES_PER_S = 16 << 20
+
 
 class BBCheckpointManager:
     def __init__(self, system: BurstBufferSystem, *,
@@ -61,6 +65,7 @@ class BBCheckpointManager:
         self._clock = clock
         self.saved_steps: List[int] = []
         self._flush_threads: List[threading.Thread] = []
+        self._unflushed: List[int] = []     # flushes the cluster did not ack
         self.metrics: Dict[int, dict] = {}
         # telemetry (ISSUE 9): save/restore latency histograms; save() and
         # restore() also open trace roots, so one checkpoint becomes a span
@@ -116,20 +121,31 @@ class BBCheckpointManager:
 
         epoch = step
         if blocking_flush:
-            self.system.flush(epoch)
+            timeout = self._flush_timeout(step)
+            if not self.system.flush(epoch, timeout=timeout):
+                raise TimeoutError(f"checkpoint flush of step {step} not "
+                                   f"acknowledged in {timeout:.0f} s")
             self._retire(step)
         else:
             t = threading.Thread(target=self._flush_async,
-                                 args=(epoch, step), daemon=True)
+                                 args=(epoch, step), daemon=True,
+                                 name=f"ckpt-flush-{step}")
             t.start()
             self._flush_threads.append(t)
         return ingest_s
 
+    def _flush_timeout(self, step: int) -> float:
+        return max(self.ack_timeout,
+                   self.metrics[step]["bytes"] / FLUSH_FLOOR_BYTES_PER_S)
+
     def _flush_async(self, epoch: int, step: int):
         t0 = self._clock()
         with telemetry.span("ckpt.flush", "checkpoint", step=step):
-            self.system.flush(epoch)
+            done = self.system.flush(epoch,
+                                     timeout=self._flush_timeout(step))
         self.metrics[step]["flush_s"] = self._clock() - t0
+        if not done:
+            self._unflushed.append(step)
         self._retire(step)
 
     def _retire(self, step: int):
@@ -141,10 +157,23 @@ class BBCheckpointManager:
                 self.system.evict(f"ckpt_{s:08d}")
                 self.saved_steps.remove(s)
 
-    def wait_flushes(self, timeout: float = 60.0):
+    def wait_flushes(self, timeout: Optional[float] = None):
+        """Wait for the background flushes, each bounded by its own deadline
+        (``ack_timeout``, longer for a checkpoint too large to flush in it
+        at FLUSH_FLOOR_BYTES_PER_S), or for at most ``timeout`` seconds.
+        Raises TimeoutError naming the flushes still running and those the
+        cluster did not acknowledge in time."""
+        deadline = None if timeout is None else time.monotonic() + timeout
         for t in self._flush_threads:
-            t.join(timeout)
-        self._flush_threads = []
+            t.join(None if deadline is None
+                   else max(0.0, deadline - time.monotonic()))
+        self._flush_threads = [t for t in self._flush_threads if t.is_alive()]
+        unflushed, self._unflushed = self._unflushed, []
+        if self._flush_threads or unflushed:
+            raise TimeoutError(
+                f"checkpoint flush not finished: still running "
+                f"{[t.name for t in self._flush_threads]}, not acknowledged "
+                f"for steps {unflushed}")
 
     # --------------------------------------------------------------- restore
     def latest_step(self) -> Optional[int]:
@@ -157,7 +186,7 @@ class BBCheckpointManager:
         return max(steps) if steps else None
 
     def restore(self, target_state, step: Optional[int] = None, *,
-                stage: bool = True):
+                stage: bool = True, shardings: Optional[dict] = None):
         """Rebuild a train state. target_state provides structure/shapes
         (e.g. a freshly-initialized state). All reads go through BBFile
         handles, whose pread already prefers buffered chunks, then the
@@ -170,7 +199,12 @@ class BBCheckpointManager:
         time. Staging is best-effort — if the manager is busy or a server
         dies mid-stage, the handle's read fallback chain still returns
         byte-exact data — and the payload handle keeps ``prefetch`` on so
-        any unstaged tail is read ahead of the loop."""
+        any unstaged tail is read ahead of the loop.
+
+        ``shardings`` maps leaf names (``serializer.tree_paths``) to the
+        sharding each restored leaf is placed with.
+
+        Raises FileNotFoundError when there is no checkpoint to restore."""
         step = step if step is not None else self.latest_step()
         if step is None:
             raise FileNotFoundError("no checkpoint found")
@@ -192,6 +226,7 @@ class BBCheckpointManager:
                 for meta in manifest["leaves"]:
                     payloads[meta["name"]] = f.pread(meta["offset"],
                                                      meta["nbytes"])
-            out = ser.deserialize_tree(target_state, payloads, manifest)
+            out = ser.deserialize_tree(target_state, payloads, manifest,
+                                       shardings)
         self._m_restore.observe(self._clock() - t0)
         return out, step

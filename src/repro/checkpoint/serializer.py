@@ -92,6 +92,23 @@ def deserialize_leaf(payload: bytes, meta: dict):
     return x.reshape(shape).astype(meta["dtype"])
 
 
+def leaf_nbytes(leaf, quantize: bool) -> int:
+    """Payload bytes ``serialize_leaf`` writes for a leaf of this shape and
+    dtype (an array or a ShapeDtypeStruct)."""
+    n = int(np.prod(leaf.shape))
+    if not quantize:
+        return n * np.dtype(leaf.dtype).itemsize
+    nb = -(-n // QUANT_BLOCK)
+    return nb * QUANT_BLOCK + 4 * nb
+
+
+def tree_nbytes(tree, quant_policy: Optional[Callable] = None) -> int:
+    """Checkpoint bytes of a tree, from shapes alone."""
+    quant_policy = quant_policy or (lambda p, l: False)
+    return sum(leaf_nbytes(leaf, quant_policy(name, leaf))
+               for name, leaf in tree_paths(tree))
+
+
 def serialize_tree(tree, quant_policy: Optional[Callable] = None
                    ) -> Tuple[Dict[str, bytes], dict]:
     """Returns ({key: payload}, manifest). Manifest records order, offsets
@@ -110,18 +127,22 @@ def serialize_tree(tree, quant_policy: Optional[Callable] = None
     return payloads, manifest
 
 
-def deserialize_tree(target_tree, payloads: Dict[str, bytes], manifest: dict):
+def deserialize_tree(target_tree, payloads: Dict[str, bytes], manifest: dict,
+                     shardings: Optional[Dict[str, Any]] = None):
     """Rebuild arrays in the structure of target_tree (an example pytree,
-    e.g. jax.eval_shape output or a freshly-initialized state)."""
+    e.g. jax.eval_shape output or a freshly-initialized state).
+
+    ``shardings`` maps leaf names to the sharding each is placed with,
+    straight from the host; other leaves go to the default device."""
     metas = {m["name"]: m for m in manifest["leaves"]}
+    shardings = shardings or {}
     flat, treedef = jax.tree_util.tree_flatten_with_path(target_tree)
     leaves = []
     for path, leaf in flat:
         name = "/".join(_path_str(p) for p in path)
-        meta = metas[name]
-        arr = deserialize_leaf(payloads[name], meta)
-        leaves.append(jnp.asarray(arr))
-    return jax.tree_util.tree_unflatten(treedef, [l for l in leaves])
+        arr = deserialize_leaf(payloads[name], metas[name])
+        leaves.append(jax.device_put(arr, shardings.get(name)))
+    return jax.tree_util.tree_unflatten(treedef, leaves)
 
 
 def manifest_bytes(manifest: dict) -> bytes:

@@ -9,9 +9,11 @@ two matmuls per block hit the MXU with 128-aligned shapes.
 
 Supports: causal masking, sliding-window masking, gemma-style logit softcap,
 GQA (kv-head indexed via the BlockSpec index_map — no materialized repeat).
-Fully-masked KV blocks are skipped via the grid bounds per q-block row
-(causal/window block pruning happens in the index domain, not with @pl.when,
-so skipped blocks are never fetched).
+Every (q block, KV block) pair is visited; masked scores are set to -inf.
+
+With ``return_stats`` the kernel also writes each row's softmax statistics
+(running max m and sum l), which the flash backward in ``kernels/ops.py``
+needs to recompute probabilities chunkwise.
 """
 from __future__ import annotations
 
@@ -26,9 +28,12 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-def _attn_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
-                 block_q, block_k, seq_k, causal, window, softcap, scale,
-                 q_offset):
+def _attn_kernel(q_ref, k_ref, v_ref, *refs, block_q, block_k, seq_k,
+                 causal, window, softcap, scale, q_offset, return_stats):
+    if return_stats:
+        o_ref, m_ref, l_ref, m_scr, l_scr, acc_scr = refs
+    else:
+        o_ref, m_scr, l_scr, acc_scr = refs
     qi = pl.program_id(2)
     ki = pl.program_id(3)
     nk = pl.num_programs(3)
@@ -75,12 +80,19 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
     def _finalize():
         denom = jnp.maximum(l_scr[...], 1e-30)
         o_ref[0, 0] = (acc_scr[...] / denom).astype(o_ref.dtype)
+        if return_stats:
+            m_ref[0, 0] = m_scr[...]
+            l_ref[0, 0] = l_scr[...]
 
 
 def flash_attention_pallas(q, k, v, *, causal=True, window=0, softcap=0.0,
                            q_offset=0, block_q=128, block_k=128,
-                           interpret=False):
-    """q: (B, Sq, H, D); k/v: (B, Sk, KV, D) -> (B, Sq, H, D)."""
+                           return_stats=False, interpret=False):
+    """q: (B, Sq, H, D); k/v: (B, Sk, KV, D) -> (B, Sq, H, D).
+
+    With ``return_stats``: (o, m, l), where m and l are the f32 row max and
+    row sum of exp(s - m) over the scaled, softcapped, masked scores, shaped
+    (B, Sq, KV, H // KV) as ``ops._flash_bwd`` takes them."""
     b, sq, h, d = q.shape
     sk, kvh = k.shape[1], k.shape[2]
     assert h % kvh == 0
@@ -107,9 +119,20 @@ def flash_attention_pallas(q, k, v, *, causal=True, window=0, softcap=0.0,
     kernel = functools.partial(
         _attn_kernel, block_q=block_q, block_k=block_k, seq_k=sk,
         causal=causal, window=window, softcap=softcap, scale=d ** -0.5,
-        q_offset=q_offset)
+        q_offset=q_offset, return_stats=return_stats)
 
-    out = pl.pallas_call(
+    o_spec = pl.BlockSpec((1, 1, block_q, d),
+                          lambda b_, h_, qi, ki: (b_, h_, qi, 0))
+    out_specs = [o_spec]
+    out_shape = [jax.ShapeDtypeStruct((b, h, sq_p, d), q.dtype)]
+    if return_stats:
+        stat_spec = pl.BlockSpec((1, 1, block_q, 1),
+                                 lambda b_, h_, qi, ki: (b_, h_, qi, 0))
+        stat_shape = jax.ShapeDtypeStruct((b, h, sq_p, 1), jnp.float32)
+        out_specs += [stat_spec, stat_spec]
+        out_shape += [stat_shape, stat_shape]
+
+    outs = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
@@ -119,9 +142,8 @@ def flash_attention_pallas(q, k, v, *, causal=True, window=0, softcap=0.0,
             pl.BlockSpec((1, 1, block_k, d),
                          lambda b_, h_, qi, ki, g=group: (b_, h_ // g, ki, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, block_q, d),
-                               lambda b_, h_, qi, ki: (b_, h_, qi, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, h, sq_p, d), q.dtype),
+        out_specs=out_specs,
+        out_shape=out_shape,
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),   # running max
             pltpu.VMEM((block_q, 1), jnp.float32),   # running sum
@@ -130,7 +152,11 @@ def flash_attention_pallas(q, k, v, *, causal=True, window=0, softcap=0.0,
         interpret=interpret,
     )(qt, kt, vt)
 
-    out = out.transpose(0, 2, 1, 3)
-    if pq:
-        out = out[:, :sq]
-    return out
+    out = outs[0].transpose(0, 2, 1, 3)[:, :sq]
+    if not return_stats:
+        return out
+
+    def stat(x):                                  # (B,H,Sq_p,1) -> (B,Sq,KV,G)
+        return x[:, :, :sq, 0].transpose(0, 2, 1).reshape(b, sq, kvh, group)
+
+    return out, stat(outs[1]), stat(outs[2])

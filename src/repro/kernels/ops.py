@@ -1,31 +1,27 @@
-"""Public kernel API. Dispatches per backend:
+"""Public kernel API. Dispatches per backend, one rule per kernel:
 
 - TPU: Pallas kernels (flash_attention.py / rg_lru.py / mlstm.py / quantize.py)
-- CPU (this container, incl. the 512-device dry-run): pure-jnp implementations
-  with the SAME blockwise structure — attention is a lax.scan over KV chunks
-  with online softmax, so the lowered HLO never materializes the S x S score
-  matrix and the dry-run's memory/FLOP profile matches the fused kernel.
+- CPU (incl. the 512-device dry-run): pure-jnp implementations with the SAME
+  blockwise structure — attention is a lax.scan over KV chunks with online
+  softmax, so the lowered HLO never materializes the S x S score matrix and
+  the dry-run's memory/FLOP profile matches the fused kernel.
 
-Set REPRO_FORCE_INTERPRET=1 to run the real Pallas kernels in interpret mode
-(used by kernel unit tests).
+Kernel unit tests call the Pallas entry points with ``interpret=True``.
 """
 from __future__ import annotations
 
 import functools
-import os
+import math
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.kernels import ref
 
 
 def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
-
-
-def _force_interpret() -> bool:
-    return os.environ.get("REPRO_FORCE_INTERPRET", "0") == "1"
 
 
 # ---------------------------------------------------------------------------
@@ -36,17 +32,64 @@ def flash_attention(q, k, v, *, causal=True, window=0, softcap=0.0,
                     q_offset=0, chunk=512):
     """q: (B,Sq,H,D); k/v: (B,Sk,KV,D) -> (B,Sq,H,D).
 
-    Differentiable with a FLASH BACKWARD (custom VJP): the forward saves only
-    (o, m, l); the backward recomputes scores chunkwise. Without this,
-    differentiating through the online-softmax scan saves every chunk's
-    probability matrix — measured at ~16 GB per layer on train_4k shapes.
+    Differentiable with a FLASH BACKWARD (custom VJP) on both backends: the
+    forward saves only (o, m, l); the backward recomputes scores chunkwise.
+    Without this, differentiating through the online-softmax scan saves every
+    chunk's probability matrix — measured at ~16 GB per layer on train_4k
+    shapes. On TPU the forward is the Pallas kernel, run per shard.
     """
-    if _on_tpu() or _force_interpret():
-        from repro.kernels.flash_attention import flash_attention_pallas
-        return flash_attention_pallas(
-            q, k, v, causal=causal, window=window, softcap=softcap,
-            q_offset=q_offset, interpret=not _on_tpu())
+    if _on_tpu():
+        return _per_shard(
+            lambda q, k, v: _flash_pallas(q, k, v, causal, window, softcap,
+                                          q_offset, chunk, False), q, k, v)
     return _flash_vjp(q, k, v, causal, window, softcap, q_offset, chunk)
+
+
+def _per_shard(fn, q, k, v):
+    """Run an attention kernel on each shard of the active rule set's mesh.
+
+    XLA cannot partition a Mosaic kernel, so under a rule set the call goes
+    through ``shard_map``: batch over the batch axes, heads over the heads
+    axis when the query and KV head counts both divide it (each shard then
+    holds whole GQA groups). The sequence stays whole on every shard."""
+    from repro.launch.sharding import active_rules
+    rules = active_rules()
+    if rules is None:
+        return fn(q, k, v)
+    batch, _, heads, _ = rules.spec(("batch", None, "heads", None), q.shape)
+    if heads is not None:
+        names = heads if isinstance(heads, tuple) else (heads,)
+        if k.shape[2] % math.prod(rules.sizes[a] for a in names):
+            heads = None
+    spec = P(batch, None, heads, None)
+    return jax.shard_map(fn, mesh=rules.mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(q, k, v)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash_pallas(q, k, v, causal, window, softcap, q_offset, chunk,
+                  interpret):
+    from repro.kernels.flash_attention import flash_attention_pallas
+    return flash_attention_pallas(q, k, v, causal=causal, window=window,
+                                  softcap=softcap, q_offset=q_offset,
+                                  interpret=interpret)
+
+
+def _flash_pallas_fwd(q, k, v, causal, window, softcap, q_offset, chunk,
+                      interpret):
+    from repro.kernels.flash_attention import flash_attention_pallas
+    o, m, l = flash_attention_pallas(q, k, v, causal=causal, window=window,
+                                     softcap=softcap, q_offset=q_offset,
+                                     return_stats=True, interpret=interpret)
+    return o, (q, k, v, o, m, l)
+
+
+def _flash_pallas_bwd(causal, window, softcap, q_offset, chunk, interpret,
+                      res, g_out):
+    return _flash_bwd(causal, window, softcap, q_offset, chunk, res, g_out)
+
+
+_flash_pallas.defvjp(_flash_pallas_fwd, _flash_pallas_bwd)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
@@ -187,9 +230,9 @@ def _flash_chunked_jnp(q, k, v, *, causal, window, softcap, q_offset, chunk,
 
 def rg_lru(a, gx, h0=None):
     """h_t = a_t * h_{t-1} + gx_t. a/gx: (B,S,D) -> (h, h_last)."""
-    if _on_tpu() or _force_interpret():
+    if _on_tpu():
         from repro.kernels.rg_lru import rg_lru_pallas
-        return rg_lru_pallas(a, gx, h0, interpret=not _on_tpu())
+        return rg_lru_pallas(a, gx, h0)
     return _rg_lru_assoc(a, gx, h0)
 
 
@@ -216,9 +259,9 @@ def _rg_lru_assoc(a, gx, h0=None):
 
 def mlstm(q, k, v, log_f, log_i, state=None, chunk=128):
     """Chunkwise mLSTM. state: optional (C, n, m) carry (decode path)."""
-    if state is None and (_on_tpu() or _force_interpret()):
+    if state is None and _on_tpu():
         from repro.kernels.mlstm import mlstm_pallas
-        return mlstm_pallas(q, k, v, log_f, log_i, interpret=not _on_tpu())
+        return mlstm_pallas(q, k, v, log_f, log_i)
     s = q.shape[1]
     if s > 1 and s % min(chunk, s) == 0:
         return _mlstm_chunked_jnp(q, k, v, log_f, log_i, state,
@@ -301,17 +344,15 @@ def _mlstm_chunked_jnp(q, k, v, log_f, log_i, state=None, chunk=128):
 
 
 def quantize_blockwise(x, *, block=2048):
-    if _on_tpu() or _force_interpret():
+    if _on_tpu():
         from repro.kernels.quantize import quantize_blockwise_pallas
-        return quantize_blockwise_pallas(x, block=block,
-                                         interpret=not _on_tpu())
+        return quantize_blockwise_pallas(x, block=block)
     return ref.quantize_blockwise(x, block)
 
 
 def dequantize_blockwise(q, scale, *, block=2048, out_dtype=jnp.float32):
-    if _on_tpu() or _force_interpret():
+    if _on_tpu():
         from repro.kernels.quantize import dequantize_blockwise_pallas
         return dequantize_blockwise_pallas(q, scale, block=block,
-                                           out_dtype=out_dtype,
-                                           interpret=not _on_tpu())
+                                           out_dtype=out_dtype)
     return ref.dequantize_blockwise(q, scale, block).astype(out_dtype)

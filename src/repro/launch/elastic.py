@@ -15,9 +15,9 @@ from __future__ import annotations
 
 from typing import Dict, List, Optional, Sequence, Tuple
 
-import jax
 import numpy as np
 
+from repro.checkpoint.serializer import tree_paths
 from repro.launch.mesh import make_host_mesh
 from repro.launch.sharding import RuleSet
 
@@ -41,18 +41,17 @@ def reshard_plan(cfg, model, optimizer, mesh) -> Tuple[RuleSet, object]:
 def elastic_restore(mgr, cfg, model, optimizer, mesh, target_state,
                     step: Optional[int] = None):
     """Restore a BB checkpoint onto a (possibly different) mesh: values are
-    fetched by logical key, then device_put with the new shardings."""
+    fetched by logical key and each is placed from the host straight into
+    its sharding on the new mesh. Returns ({"params", "opt_state"}, step)."""
     rules, axes = reshard_plan(cfg, model, optimizer, mesh)
-    restored, ck_step = mgr.restore(target_state, step)
+    tree = {"params": target_state["params"],
+            "opt_state": target_state["opt_state"]}
     shardings = rules.tree_shardings(
-        {"params": axes.params, "opt_state": axes.opt_state},
-        {"params": restored["params"], "opt_state": restored["opt_state"]})
-    with mesh:
-        placed = jax.tree.map(jax.device_put,
-                              {"params": restored["params"],
-                               "opt_state": restored["opt_state"]},
-                              shardings)
-    return placed, ck_step
+        {"params": axes.params, "opt_state": axes.opt_state}, tree)
+    restored, ck_step = mgr.restore(target_state, step,
+                                    shardings=dict(tree_paths(shardings)))
+    return {"params": restored["params"],
+            "opt_state": restored["opt_state"]}, ck_step
 
 
 def rebalance_domains(flush_throughput: Dict[str, float],

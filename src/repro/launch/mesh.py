@@ -11,16 +11,24 @@ touch jax device state (the dry-run sets XLA_FLAGS before first jax init).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _mesh(shape, axes):
+    """Auto axes: sharding is resolved by the rules' constraints, which JAX
+    refuses on the Explicit axes that ``jax.make_mesh`` defaults to."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1, pod: int = 0):
-    """Small mesh for tests (requires xla_force_host_platform_device_count)."""
+    """Small mesh over this host's devices: a four-chip host's chips, or the
+    CPU devices that xla_force_host_platform_device_count makes in tests."""
     if pod:
-        return jax.make_mesh((pod, data, model), ("pod", "data", "model"))
-    return jax.make_mesh((data, model), ("data", "model"))
+        return _mesh((pod, data, model), ("pod", "data", "model"))
+    return _mesh((data, model), ("data", "model"))

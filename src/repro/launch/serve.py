@@ -14,6 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import get_config, reduced
+from repro.launch.jax_cache import use_compile_cache
 from repro.models.registry import build_model
 from repro.runtime.serve_step import greedy_token, make_decode_step, \
     make_prefill
@@ -50,6 +51,7 @@ def main():
     ap.add_argument("--requests", type=int, default=3)
     args = ap.parse_args()
 
+    use_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = reduced(cfg)

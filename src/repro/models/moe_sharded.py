@@ -32,11 +32,8 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-try:
-    from jax import shard_map
-except ImportError:                      # older jax
-    from jax.experimental.shard_map import shard_map
 
 from repro.models.common import activation
 

@@ -62,6 +62,51 @@ def test_chunked_jnp_flash_vs_oracle(case):
                                atol=tol, rtol=tol)
 
 
+@pytest.mark.parametrize("case", [
+    # (B, S, H, KV, D, causal, window, softcap)
+    (1, 128, 4, 2, 64, True, 0, 0.0),
+    (2, 80, 4, 4, 48, True, 0, 0.0),           # ragged seq (padded blocks)
+    (1, 128, 8, 2, 64, True, 48, 20.0),        # window + softcap
+])
+def test_flash_pallas_grads_match_jnp_vjp(case):
+    """The TPU branch (Pallas forward + flash backward) differentiates to
+    the same q/k/v gradients as the CPU branch's custom VJP."""
+    b, s, h, kv, d, causal, window, cap = case
+    q = _rand((b, s, h, d), jnp.float32)
+    k = _rand((b, s, kv, d), jnp.float32)
+    v = _rand((b, s, kv, d), jnp.float32)
+    w = _rand((b, s, h, d), jnp.float32)
+
+    def loss(fn):
+        return lambda q, k, v: jnp.sum(fn(q, k, v) * w)
+
+    pallas = loss(lambda q, k, v: ops._flash_pallas(
+        q, k, v, causal, window, cap, 0, 32, True))
+    jnp_vjp = loss(lambda q, k, v: ops._flash_vjp(
+        q, k, v, causal, window, cap, 0, 32))
+    got = jax.grad(pallas, argnums=(0, 1, 2))(q, k, v)
+    exp = jax.grad(jnp_vjp, argnums=(0, 1, 2))(q, k, v)
+    for g, e in zip(got, exp):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(e),
+                                   atol=1e-4, rtol=1e-4)
+
+
+def test_flash_pallas_stats_match_jnp():
+    """The kernel's (m, l) equal the jnp forward's, layout included."""
+    q = _rand((2, 96, 4, 32), jnp.float32)
+    k = _rand((2, 96, 2, 32), jnp.float32)
+    v = _rand((2, 96, 2, 32), jnp.float32)
+    o, m, l = flash_attention_pallas(q, k, v, block_q=64, block_k=64,
+                                     return_stats=True, interpret=True)
+    oj, mj, lj = ops._flash_chunked_jnp(q, k, v, causal=True, window=0,
+                                        softcap=0.0, q_offset=0, chunk=32,
+                                        return_stats=True)
+    np.testing.assert_allclose(np.asarray(o), np.asarray(oj), atol=2e-5)
+    np.testing.assert_allclose(np.asarray(m), np.asarray(mj), atol=1e-5)
+    # l is a sum of exp(s - m) over a different chunking; equal to rounding
+    np.testing.assert_allclose(np.asarray(l), np.asarray(lj), rtol=1e-5)
+
+
 def test_flash_attention_q_offset_decode_consistency():
     """Chunked attention over a prefix + offset q equals full attention."""
     b, s, h, d = 1, 96, 2, 32
@@ -81,6 +126,7 @@ def test_flash_attention_q_offset_decode_consistency():
     ((2, 256, 128), (128, 128)),
     ((1, 512, 256), (256, 128)),
     ((3, 128, 384), (64, 128)),
+    ((2, 512, 256), (256, 128)),               # batch > 1 with h0 / h_last
 ])
 def test_rg_lru_vs_oracle(shape, blocks):
     b, s, d = shape
@@ -161,6 +207,21 @@ def test_quantize_roundtrip_error_bound(nblocks, seed):
     # round-trip slack: |x/s| reaches 127, so x/s, round, *s accumulates
     # ~127*eps_f32 of relative error on top of the half-step
     err = np.abs(np.asarray(xd) - np.asarray(x)).reshape(nblocks, 2048)
+    bound = np.asarray(s)[:, None] * (0.5 + 1e-4) + 1e-7
+    assert (err <= bound).all()
+
+
+@pytest.mark.parametrize("nblocks", [100, 384, 18432])
+def test_quantize_roundtrip_padded_tiling(nblocks):
+    """Block counts that are not a multiple of the tile (100, 384) or are a
+    real leaf's (18432 = 3072 x 12288 / 2048): rows are padded to the int8
+    tiling and sliced off again, under the same error bound."""
+    rng = np.random.default_rng(nblocks)
+    x = rng.normal(0, 3.0, nblocks * 2048).astype(np.float32)
+    q, s = quantize_blockwise_pallas(jnp.asarray(x), interpret=True)
+    assert q.shape == (nblocks * 2048,) and s.shape == (nblocks,)
+    xd = dequantize_blockwise_pallas(q, s, interpret=True)
+    err = np.abs(np.asarray(xd) - x).reshape(nblocks, 2048)
     bound = np.asarray(s)[:, None] * (0.5 + 1e-4) + 1e-7
     assert (err <= bound).all()
 
