@@ -20,6 +20,15 @@ entirely against the BBFileSystem file-session API:
      pressure mid-training — a restore spanning drained data is byte-exact
      without the checkpoint manager knowing anything moved.
 
+With telemetry enabled, a save is two trace roots that share its step:
+``ckpt.serialize`` (each leaf's ``ckpt.fetch`` and ``ckpt.quantize``) and
+``ckpt.save`` (``ckpt.pwrite``, then ``ckpt.barrier``); the background flush
+is a third, ``ckpt.flush``, with ``acked`` and ``bytes``. A restore is one,
+``ckpt.restore`` (``ckpt.stage``, ``ckpt.read``, then each leaf's
+``ckpt.dequantize`` and ``ckpt.place``). The checkpoint roots record
+``rss_peak_bytes``, the process's resident peak while they were open
+(``repro.checkpoint.tracing``).
+
 When the servers run with the drain engine enabled (the default), save()
 records the cluster pressure snapshot alongside ingest timings, so training
 logs show how close the buffer ran to its watermarks at each step.
@@ -40,6 +49,7 @@ import time
 from typing import Callable, Dict, List, Optional
 
 from repro.checkpoint import serializer as ser
+from repro.checkpoint import tracing
 from repro.core import telemetry
 from repro.core.system import BurstBufferSystem
 
@@ -66,6 +76,7 @@ class BBCheckpointManager:
         self.saved_steps: List[int] = []
         self._flush_threads: List[threading.Thread] = []
         self._unflushed: List[int] = []     # flushes the cluster did not ack
+        self._acked: Dict[int, bool] = {}   # step -> its flush was acked
         self.metrics: Dict[int, dict] = {}
         # telemetry (ISSUE 9): save/restore latency histograms; save() and
         # restore() also open trace roots, so one checkpoint becomes a span
@@ -85,7 +96,8 @@ class BBCheckpointManager:
         mode = io_mode or self.io_mode
         t0 = self._clock()
         policy = ser.default_quant_policy if self.quantize else None
-        payloads, manifest = ser.serialize_tree(state, policy)
+        with tracing.root("ckpt.serialize", step=step):
+            payloads, manifest = ser.serialize_tree(state, policy)
         fname = f"ckpt_{step:08d}"
         offset_of = {m["name"]: m["offset"] for m in manifest["leaves"]}
 
@@ -95,22 +107,26 @@ class BBCheckpointManager:
         # The trace root spans the whole ingest, so every chunk put, replica
         # hop and fs RPC below parents back to this one checkpoint.
         fs = self.system.fs()
-        with telemetry.span("ckpt.save", "checkpoint", step=step):
-            f = fs.open(fname, "w", policy=mode,
-                        chunk_bytes=self.chunk_bytes, lane="checkpoint")
-            for name, data in payloads.items():
-                f.pwrite(data, offset_of[name])
-            mf = fs.open(f"{fname}.manifest", "w", policy=mode,
-                         lane="checkpoint")
-            mf.write(ser.manifest_bytes(manifest))
+        with tracing.root("ckpt.save", step=step):
+            with telemetry.child_span("ckpt.pwrite", "checkpoint", step=step,
+                                      bytes=manifest["total_bytes"]):
+                f = fs.open(fname, "w", policy=mode,
+                            chunk_bytes=self.chunk_bytes, lane="checkpoint")
+                for name, data in payloads.items():
+                    f.pwrite(data, offset_of[name])
+                mf = fs.open(f"{fname}.manifest", "w", policy=mode,
+                             lane="checkpoint")
+                mf.write(ser.manifest_bytes(manifest))
             # barrier: both handles' write pipelines must drain before the
             # checkpoint counts as ingested (paper Fig 4 thread-2); the
             # manifest barrier must run even when the data barrier raises,
             # or its failed ops would leak into the next save's drain cycle
-            try:
-                f.close(self.ack_timeout)
-            finally:
-                mf.close(self.ack_timeout)
+            with telemetry.child_span("ckpt.barrier", "checkpoint",
+                                      step=step):
+                try:
+                    f.close(self.ack_timeout)
+                finally:
+                    mf.close(self.ack_timeout)
         ingest_s = self._clock() - t0
         self._m_save.observe(ingest_s)
 
@@ -122,7 +138,8 @@ class BBCheckpointManager:
         epoch = step
         if blocking_flush:
             timeout = self._flush_timeout(step)
-            if not self.system.flush(epoch, timeout=timeout):
+            self._acked[step] = self.system.flush(epoch, timeout=timeout)
+            if not self._acked[step]:
                 raise TimeoutError(f"checkpoint flush of step {step} not "
                                    f"acknowledged in {timeout:.0f} s")
             self._retire(step)
@@ -140,13 +157,23 @@ class BBCheckpointManager:
 
     def _flush_async(self, epoch: int, step: int):
         t0 = self._clock()
-        with telemetry.span("ckpt.flush", "checkpoint", step=step):
+        with telemetry.span("ckpt.flush", "checkpoint", step=step,
+                            bytes=self.metrics[step]["bytes"]) as sp:
             done = self.system.flush(epoch,
                                      timeout=self._flush_timeout(step))
+            if sp is not telemetry.NOOP:
+                sp.args["acked"] = done
         self.metrics[step]["flush_s"] = self._clock() - t0
         if not done:
             self._unflushed.append(step)
+        self._acked[step] = done
         self._retire(step)
+
+    def flush_acked(self, step: int) -> Optional[bool]:
+        """Whether the flush of ``step``'s checkpoint to the PFS was
+        acknowledged by every live server: True once it was, False once it
+        ended without, None while it runs or for a step not saved here."""
+        return self._acked.get(step)
 
     def _retire(self, step: int):
         """Evict buffered epochs beyond the retention window (they are
@@ -211,21 +238,24 @@ class BBCheckpointManager:
         fname = f"ckpt_{step:08d}"
         fs = self.system.fs()
         t0 = self._clock()
-        with telemetry.span("ckpt.restore", "checkpoint", step=step):
+        with tracing.root("ckpt.restore", step=step):
             if stage:
                 # short deadline: a manager busy draining (likely, if
                 # pressure is why the checkpoint was evicted) must not stall
                 # the restart — the fallback chain reads byte-exact without
                 # the stage
-                fs.stage(fname, timeout=5.0)
+                with telemetry.child_span("ckpt.stage", "checkpoint",
+                                          step=step):
+                    fs.stage(fname, timeout=5.0)
 
-            with fs.open(f"{fname}.manifest", "r") as mf:
-                manifest = ser.manifest_from_bytes(mf.read())
-            payloads: Dict[str, bytes] = {}
-            with fs.open(fname, "r", prefetch=True) as f:
-                for meta in manifest["leaves"]:
-                    payloads[meta["name"]] = f.pread(meta["offset"],
-                                                     meta["nbytes"])
+            with telemetry.child_span("ckpt.read", "checkpoint", step=step):
+                with fs.open(f"{fname}.manifest", "r") as mf:
+                    manifest = ser.manifest_from_bytes(mf.read())
+                payloads: Dict[str, bytes] = {}
+                with fs.open(fname, "r", prefetch=True) as f:
+                    for meta in manifest["leaves"]:
+                        payloads[meta["name"]] = f.pread(meta["offset"],
+                                                         meta["nbytes"])
             out = ser.deserialize_tree(target_state, payloads, manifest,
                                        shardings)
         self._m_restore.observe(self._clock() - t0)

@@ -8,6 +8,11 @@ to blockwise int8 *on device* (kernels/quantize) before the host fetch,
 halving bytes into the burst buffer; f32 scales ride along. Exact dtypes are
 restored on load (quantization is applied only to leaves explicitly allowed
 by the policy — by default optimizer moments, never params/step counters).
+
+With telemetry enabled, each leaf's work is a span (``repro.checkpoint.
+tracing``) carrying ``leaf`` and ``bytes``, and the ``step`` of the span it
+opens under: ``ckpt.fetch`` and ``ckpt.quantize`` on save,
+``ckpt.dequantize`` and ``ckpt.place`` on restore.
 """
 from __future__ import annotations
 
@@ -18,6 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.checkpoint import tracing
 from repro.kernels import ops as kops
 
 QUANT_BLOCK = 2048
@@ -48,27 +54,33 @@ def default_quant_policy(path: str, leaf) -> bool:
     return head in ("opt_state",) and not path.endswith("step")
 
 
-def serialize_leaf(leaf, quantize: bool) -> Tuple[bytes, dict]:
+def serialize_leaf(leaf, quantize: bool, name: str = ""
+                   ) -> Tuple[bytes, dict]:
     """Returns (payload bytes, metadata dict)."""
-    arr = np.asarray(jax.device_get(leaf))
+    nbytes = getattr(leaf, "nbytes", None) or np.asarray(leaf).nbytes
+    with tracing.leaf("ckpt.fetch", name, nbytes):
+        arr = np.asarray(jax.device_get(leaf))
+        # bf16 has no numpy dtype name round-trip issue under ml_dtypes;
+        # store raw bytes + dtype string
+        payload = None if quantize else arr.tobytes()
     meta = {"shape": list(arr.shape), "dtype": str(arr.dtype),
             "quant": False}
     if not quantize:
-        # bf16 has no numpy dtype name round-trip issue under ml_dtypes;
-        # store raw bytes + dtype string
-        return arr.tobytes(), meta
-    flat = jnp.asarray(arr).reshape(-1).astype(jnp.float32)
-    pad = (-flat.shape[0]) % QUANT_BLOCK
-    if pad:
-        flat = jnp.pad(flat, (0, pad))
-    q, scales = kops.quantize_blockwise(flat, block=QUANT_BLOCK)
-    qb = np.asarray(jax.device_get(q)).tobytes()
-    sb = np.asarray(jax.device_get(scales), np.float32).tobytes()
+        return payload, meta
+    with tracing.leaf("ckpt.quantize", name, leaf_nbytes(leaf, True)):
+        flat = jnp.asarray(arr).reshape(-1).astype(jnp.float32)
+        pad = (-flat.shape[0]) % QUANT_BLOCK
+        if pad:
+            flat = jnp.pad(flat, (0, pad))
+        q, scales = kops.quantize_blockwise(flat, block=QUANT_BLOCK)
+        qb = np.asarray(jax.device_get(q)).tobytes()
+        sb = np.asarray(jax.device_get(scales), np.float32).tobytes()
+        payload = qb + sb
     meta.update(quant=True, pad=int(pad), nq=len(qb), block=QUANT_BLOCK)
-    return qb + sb, meta
+    return payload, meta
 
 
-def deserialize_leaf(payload: bytes, meta: dict):
+def deserialize_leaf(payload: bytes, meta: dict, name: str = ""):
     shape = tuple(meta["shape"])
     dtype = np.dtype(meta["dtype"]) if meta["dtype"] != "bfloat16" else None
     if not meta["quant"]:
@@ -78,18 +90,20 @@ def deserialize_leaf(payload: bytes, meta: dict):
         else:
             arr = np.frombuffer(payload, dtype=dtype)
         return arr.reshape(shape)
-    nq = meta["nq"]
-    q = np.frombuffer(payload[:nq], dtype=np.int8)
-    scales = np.frombuffer(payload[nq:], dtype=np.float32)
-    x = kops.dequantize_blockwise(jnp.asarray(q), jnp.asarray(scales),
-                                  block=meta["block"])
-    x = np.asarray(jax.device_get(x))
-    if meta["pad"]:
-        x = x[:-meta["pad"]]
-    if meta["dtype"] == "bfloat16":
-        import ml_dtypes
-        return x.reshape(shape).astype(ml_dtypes.bfloat16)
-    return x.reshape(shape).astype(meta["dtype"])
+    nbytes = int(np.prod(shape)) * (dtype.itemsize if dtype else 2)
+    with tracing.leaf("ckpt.dequantize", name, nbytes):
+        nq = meta["nq"]
+        q = np.frombuffer(payload[:nq], dtype=np.int8)
+        scales = np.frombuffer(payload[nq:], dtype=np.float32)
+        x = kops.dequantize_blockwise(jnp.asarray(q), jnp.asarray(scales),
+                                      block=meta["block"])
+        x = np.asarray(jax.device_get(x))
+        if meta["pad"]:
+            x = x[:-meta["pad"]]
+        if meta["dtype"] == "bfloat16":
+            import ml_dtypes
+            return x.reshape(shape).astype(ml_dtypes.bfloat16)
+        return x.reshape(shape).astype(meta["dtype"])
 
 
 def leaf_nbytes(leaf, quantize: bool) -> int:
@@ -118,7 +132,7 @@ def serialize_tree(tree, quant_policy: Optional[Callable] = None
     manifest = {"leaves": [], "treedef": None}
     offset = 0
     for name, leaf in tree_paths(tree):
-        data, meta = serialize_leaf(leaf, quant_policy(name, leaf))
+        data, meta = serialize_leaf(leaf, quant_policy(name, leaf), name)
         payloads[name] = data
         meta.update(name=name, offset=offset, nbytes=len(data))
         manifest["leaves"].append(meta)
@@ -140,8 +154,9 @@ def deserialize_tree(target_tree, payloads: Dict[str, bytes], manifest: dict,
     leaves = []
     for path, leaf in flat:
         name = "/".join(_path_str(p) for p in path)
-        arr = deserialize_leaf(payloads[name], metas[name])
-        leaves.append(jax.device_put(arr, shardings.get(name)))
+        arr = deserialize_leaf(payloads[name], metas[name], name)
+        with tracing.leaf("ckpt.place", name, arr.nbytes):
+            leaves.append(jax.device_put(arr, shardings.get(name)))
     return jax.tree_util.tree_unflatten(treedef, leaves)
 
 

@@ -141,6 +141,10 @@ def _segment(name: str) -> str:
         return "queue"
     if name.startswith("store.fsync"):
         return "fsync"
+    if name == "ckpt.barrier":
+        # a checkpoint's wait for its replicated ACKs: like a root's self
+        # time, what no handler span covers
+        return "network"
     return "service"
 
 

@@ -24,7 +24,10 @@ ISSUE 9. Three concerns, one substrate, all off by default:
   checkpoint save) becomes a span tree across client -> server -> replica
   -> manager. Only explicitly-opened roots are traced: an untraced
   message costs one dict ``.get``. ``export_chrome`` emits Chrome
-  trace-event JSON loadable in Perfetto / ``chrome://tracing``.
+  trace-event JSON loadable in Perfetto / ``chrome://tracing``. A layer
+  that imports JAX (``core/`` imports none) may install a profiler sink
+  (``set_profiler_sink``): every live span then also holds a profiler host
+  annotation open, so a profiler trace carries the spans on its own clock.
 
 - **Flight recorder**: a bounded per-component ring of recent structured
   events (epoch begin/abort/complete, evictions, redirects, timeouts,
@@ -53,7 +56,8 @@ from . import locktrack
 # go through msg_span()/trace_from() instead of reading it directly.
 TRACE_KEY = "_trace"
 
-# Every instrument the system may bind, alphabetical by name:
+# Every instrument the system may bind, and every span recorded after the
+# fact (type "span", never bound), alphabetical by name:
 # (name, type, unit, owner component, description). docs/METRICS.md is
 # rendered from this tuple (tools/bbcheck --emit-metrics); binding a name
 # that is not declared here raises, which is what keeps the doc honest.
@@ -79,6 +83,15 @@ CATALOG: Tuple[Tuple[str, str, str, str, str], ...] = (
     ("health.eval_s", "histogram", "seconds", "health",
      "Wall time of one HealthEngine.evaluate() pass over a registry "
      "snapshot."),
+    ("jax.compile", "span", "seconds", "jax",
+     "One backend compile (or load from the persistent compilation "
+     "cache), recorded when JAX reports its duration as a completed "
+     "child of the compiling thread's innermost open span, with that "
+     "span's step and name (in=)."),
+    ("jax.compiles", "counter", "count", "jax",
+     "Backend compiles (or persistent-cache loads) while telemetry is "
+     "enabled, keyed by the name of the compiling thread's innermost open "
+     "span (empty: none open)."),
     ("manager.drain_epoch_s", "histogram", "seconds", "manager",
      "Drain micro-epoch duration, drain_request arrival to the last "
      "flush_done."),
@@ -273,7 +286,7 @@ class Span:
     it carries ``[trace_id, span_id]`` to the receiver."""
 
     __slots__ = ("_tracer", "name", "component", "trace_id", "span_id",
-                 "parent_id", "args", "_t0")
+                 "parent_id", "args", "_t0", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, component: str,
                  trace_id: int, parent_id: int, args: dict):
@@ -285,10 +298,14 @@ class Span:
         self.parent_id = parent_id
         self.args = args
         self._t0 = 0.0
+        self._ann = None
 
     def __enter__(self) -> "Span":
         self._t0 = self._tracer._clock()
         _SPANS.stack.append(self)
+        if _annotator is not None:
+            self._ann = _annotator(self.name, **self.args)
+            self._ann.__enter__()
         return self
 
     def __exit__(self, *exc):
@@ -300,6 +317,9 @@ class Span:
                 st.remove(self)
             except ValueError:
                 pass
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
         self._tracer._finish(self, self._tracer._clock())
         return False
 
@@ -501,18 +521,48 @@ class Registry:
 # an operator) opted in before constructing the system.
 _registry: Optional[Registry] = None
 
+# The profiler sink (``set_profiler_sink``): an annotator called as
+# ``annotator(name, **args)`` for a context manager that each live Span
+# holds open while it is, and the hooks run as telemetry is enabled and
+# disabled.
+_annotator: Optional[Callable[..., Any]] = None
+_on_enable: Optional[Callable[[], None]] = None
+_on_disable: Optional[Callable[[], None]] = None
+
 
 def enable(clock: Callable[[], float] = time.monotonic) -> Registry:
     """Idempotent: returns the existing registry if already enabled."""
     global _registry
     if _registry is None:
         _registry = Registry(clock)
+        if _on_enable is not None:
+            _on_enable()
     return _registry
 
 
 def disable():
     global _registry
+    if _registry is not None and _on_disable is not None:
+        _on_disable()
     _registry = None
+
+
+def set_profiler_sink(annotator: Optional[Callable[..., Any]],
+                      on_enable: Optional[Callable[[], None]] = None,
+                      on_disable: Optional[Callable[[], None]] = None):
+    """Install the profiler's pieces, for a layer that imports the
+    profiler (``core/`` imports no JAX): ``annotator(name, **args)`` makes
+    the context manager every live Span enters with itself and exits with
+    itself, so a profiler trace holds the span on the profiler's clock and
+    thread; ``on_enable``/``on_disable`` run as telemetry is enabled and
+    disabled (and at once, if it is enabled now). Spans recorded after the
+    fact (``observe_span``, ``observe_child``) are never annotated."""
+    global _annotator, _on_enable, _on_disable
+    if _registry is not None and _on_disable is not None:
+        _on_disable()
+    _annotator, _on_enable, _on_disable = annotator, on_enable, on_disable
+    if _registry is not None and on_enable is not None:
+        on_enable()
 
 
 def enabled() -> bool:
@@ -591,6 +641,29 @@ def observe_span(name: str, component: str, ctx, t0: float, dur: float,
     reg = _registry
     if reg is not None:
         reg.tracer.observe(name, component, ctx, t0, dur, **args)
+
+
+def observe_child(name: str, component: str, dur: float, **args):
+    """Record a span of ``dur`` seconds that ended just now as a child of
+    this thread's innermost open span, with that span's ``step`` and name
+    (``in``) among its args, so a reader of flat (name, duration, args)
+    records can place it: for work timed by someone else and reported
+    when it ends (a JAX compile). Nothing when telemetry is off or no span
+    is open."""
+    reg = _registry
+    top = current_span()
+    if reg is None or top is None:
+        return
+    args.update({"step": top.args.get("step"), "in": top.name})
+    reg.tracer.observe(name, component, [top.trace_id, top.span_id],
+                       reg._clock() - dur, dur, **args)
+
+
+def current_span() -> Optional[Span]:
+    """This thread's innermost open span, or None (always None while
+    telemetry is off)."""
+    st = _SPANS.stack
+    return st[-1] if _registry is not None and st else None
 
 
 def current_ctx() -> Optional[List[int]]:

@@ -248,6 +248,22 @@ def test_attribution_uncovered_root_time_is_network():
     assert op["segments"]["service"]["share"] == pytest.approx(0.4)
 
 
+def test_attribution_checkpoint_barrier_wait_is_network():
+    """A save's wait for its replicated ACKs (``ckpt.barrier``) is time no
+    handler covers, as the root's own; its submit loop is service."""
+    eng = _engine()
+    tr = FakeTracer([
+        _ev(4, 1, 0, "ckpt.save", 10.0),
+        _ev(4, 2, 1, "ckpt.pwrite", 4.0),
+        _ev(4, 3, 1, "ckpt.barrier", 6.0),
+    ])
+    eng.evaluate({}, tracer=tr, now=0.0)
+    r = eng.evaluate({}, tracer=tr, now=1.0)
+    op = r["bottlenecks"]["ops"]["ckpt.save"]
+    assert op["segments"]["network"]["share"] == pytest.approx(0.6)
+    assert op["segments"]["service"]["share"] == pytest.approx(0.4)
+
+
 def test_attribution_waits_for_straggler_spans():
     """A trace is attributed one evaluation after its last span lands, so
     spans finishing across threads between cadences still count."""

@@ -160,6 +160,89 @@ def test_span_tree_and_chrome_export(tmp_path):
     assert xs[0]["dur"] == pytest.approx(0.5e6)    # microseconds
 
 
+class _Annotation:
+    """A stand-in for the profiler's annotation: logs its life."""
+
+    log = []
+
+    def __init__(self, name, **args):
+        self.name = name
+        self.log.append(("new", name, args))
+
+    def __enter__(self):
+        self.log.append(("enter", self.name))
+
+    def __exit__(self, *exc):
+        self.log.append(("exit", self.name))
+
+
+@pytest.fixture
+def annotations(monkeypatch):
+    """Telemetry off, with a logging annotator as its profiler sink."""
+    log = []
+    monkeypatch.setattr(_Annotation, "log", log)
+    monkeypatch.setattr(telemetry, "_registry", None)
+    monkeypatch.setattr(telemetry, "_annotator", _Annotation)
+    monkeypatch.setattr(telemetry, "_on_enable", None)
+    monkeypatch.setattr(telemetry, "_on_disable", None)
+    return log
+
+
+def test_annotator_wraps_each_live_span_in_order(annotations, monkeypatch):
+    monkeypatch.setattr(telemetry, "_registry",
+                        telemetry.Registry(clock=FakeClock()))
+    with telemetry.span("op", "app", step=4):
+        with telemetry.child_span("leaf", "app", step=4, leaf="w"):
+            assert annotations[-1] == ("enter", "leaf")
+        telemetry.observe_span("wait", "app", telemetry.current_ctx(),
+                               100.0, 0.5)
+        telemetry.observe_child("jax.compile", "jax", 0.25)
+    assert annotations == [
+        ("new", "op", {"step": 4}), ("enter", "op"),
+        ("new", "leaf", {"step": 4, "leaf": "w"}), ("enter", "leaf"),
+        ("exit", "leaf"), ("exit", "op")]
+    names = [e[3] for e in telemetry.registry().tracer.events()]
+    assert names == ["leaf", "wait", "jax.compile", "op"]
+
+
+def test_annotator_never_called_with_telemetry_off(annotations):
+    with telemetry.span("op", "app", step=1):
+        with telemetry.child_span("leaf", "app"):
+            pass
+    telemetry.observe_span("wait", "app", [1, 2], 0.0, 1.0)
+    telemetry.observe_child("jax.compile", "jax", 0.25)
+    assert annotations == []
+    assert telemetry.current_span() is None
+
+
+def test_profiler_sink_hooks_follow_enable_and_disable(annotations,
+                                                      monkeypatch):
+    calls = []
+    telemetry.set_profiler_sink(_Annotation, lambda: calls.append("on"),
+                                lambda: calls.append("off"))
+    assert calls == []                      # off: nothing attached yet
+    telemetry.enable()
+    telemetry.enable()                      # idempotent: attached once
+    assert calls == ["on"]
+    telemetry.set_profiler_sink(_Annotation, lambda: calls.append("on2"))
+    assert calls == ["on", "off", "on2"]    # swapped while enabled
+    telemetry.disable()
+    assert calls == ["on", "off", "on2"]    # the new sink has no detach
+
+
+def test_observe_child_takes_the_innermost_span_step_and_name(monkeypatch):
+    reg = telemetry.Registry(clock=FakeClock(50.0))
+    monkeypatch.setattr(telemetry, "_registry", reg)
+    with reg.tracer.root("ckpt.save", "checkpoint", step=9) as root:
+        assert telemetry.current_span() is root
+        telemetry.observe_child("jax.compile", "jax", 2.0)
+    (compile_, _) = reg.tracer.events()
+    trace, _, parent, name, comp, t0, dur, args = compile_
+    assert (trace, parent) == (root.trace_id, root.span_id)
+    assert (name, comp, t0, dur) == ("jax.compile", "jax", 48.0, 2.0)
+    assert args == {"step": 9, "in": "ckpt.save"}
+
+
 def test_untraced_message_costs_nothing():
     reg = telemetry.Registry(clock=FakeClock())
     # no message context, no active span: msg_span refuses to open a root
@@ -365,5 +448,6 @@ def test_catalog_sorted_and_unique():
     names = [spec[0] for spec in telemetry.CATALOG]
     assert names == sorted(names)
     assert len(names) == len(set(names))
-    assert all(spec[1] in ("counter", "gauge", "histogram", "ring", "poll")
+    assert all(spec[1] in ("counter", "gauge", "histogram", "ring", "poll",
+                           "span")
                for spec in telemetry.CATALOG)

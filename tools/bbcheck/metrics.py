@@ -45,7 +45,8 @@ def render() -> str:
         "Types: **counter** (monotonic per-label), **gauge** (last write "
         "wins), **histogram** (fixed half-decade latency buckets, "
         "10µs–10s + overflow), **ring** (bounded time series), **poll** "
-        "(owner-supplied snapshot callback, read at scrape time).",
+        "(owner-supplied snapshot callback, read at scrape time), **span** "
+        "(a trace span recorded after the fact, never bound).",
         "",
     ]
     for owner in sorted(by_owner):
