@@ -21,11 +21,12 @@ entirely against the BBFileSystem file-session API:
      without the checkpoint manager knowing anything moved.
 
 With telemetry enabled, a save is two trace roots that share its step:
-``ckpt.serialize`` (each leaf's ``ckpt.fetch`` and ``ckpt.quantize``) and
-``ckpt.save`` (``ckpt.pwrite``, then ``ckpt.barrier``); the background flush
-is a third, ``ckpt.flush``, with ``acked`` and ``bytes``. A restore is one,
-``ckpt.restore`` (``ckpt.stage``, ``ckpt.read``, then each leaf's
-``ckpt.dequantize`` and ``ckpt.place``). The checkpoint roots record
+``ckpt.serialize`` (each int8 leaf's ``ckpt.quantize``, its quantization on
+the device, then each leaf's ``ckpt.fetch``, the device-to-host copy of its
+payload) and ``ckpt.save`` (``ckpt.pwrite``, then ``ckpt.barrier``); the
+background flush is a third, ``ckpt.flush``, with ``acked`` and ``bytes``.
+A restore is one, ``ckpt.restore`` (``ckpt.stage``, ``ckpt.read``, then each
+leaf's ``ckpt.dequantize`` and ``ckpt.place``). The checkpoint roots record
 ``rss_peak_bytes``, the process's resident peak while they were open
 (``repro.checkpoint.tracing``).
 

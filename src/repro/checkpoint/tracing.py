@@ -13,8 +13,11 @@ pieces into it as its profiler sink:
 
 ``root`` opens a checkpoint root span that records ``rss_peak_bytes``, the
 process's resident peak while it was open; ``leaf`` opens a child span for
-one leaf, with the ``step`` of the span it opens under. With telemetry disabled both return ``telemetry.NOOP`` and nothing
-else is made.
+one leaf, with the ``step`` of the span it opens under. On save these are
+``ckpt.quantize`` (an int8 leaf quantized on the device, closed once its
+results are ready) and ``ckpt.fetch`` (a leaf's device-to-host copies, whose
+``bytes`` are the payload that crossed the link). With telemetry disabled
+both return ``telemetry.NOOP`` and nothing else is made.
 """
 from __future__ import annotations
 

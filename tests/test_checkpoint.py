@@ -1,5 +1,10 @@
 """Checkpoint serializer + BBCheckpointManager: round-trips, quantization
-error bounds, restore fast paths, replica failover restore."""
+error bounds, what a save fetches to the host, restore fast paths, replica
+failover restore."""
+import os
+import subprocess
+import sys
+import textwrap
 import time
 
 import jax
@@ -11,6 +16,9 @@ from _hypothesis_compat import given, settings, st
 from repro.checkpoint import serializer as ser
 from repro.checkpoint.bbckpt import BBCheckpointManager
 from repro.core import BBConfig, BurstBufferSystem
+from repro.kernels import ref
+
+SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
 
 def _tree(seed=0, dtype=jnp.float32):
@@ -56,6 +64,95 @@ def test_quantized_moments_error_bound(seed):
     err = np.abs(np.asarray(out["opt_state"]["m"]) - np.asarray(leaf))
     # blockwise int8: |err| <= max|block| / 254 + eps
     assert err.max() <= np.abs(np.asarray(leaf)).max() / 127 + 1e-6
+
+
+def _moments_tree():
+    """Two int8 moments (one a whole number of blocks, one padded) beside
+    exact leaves."""
+    k = jax.random.split(jax.random.PRNGKey(4), 3)
+    return {"params": {"w": jax.random.normal(k[0], (64, 32), jnp.bfloat16)},
+            "opt_state": {
+                "m": 0.02 * jax.random.normal(k[1], (64, 32), jnp.float32),
+                "v": 0.02 * jax.random.normal(k[2], (3, 1000), jnp.float32),
+                "step": jnp.asarray(7, jnp.int32)}}
+
+
+def test_save_fetches_only_the_checkpoint(monkeypatch):
+    """An int8 leaf crosses the host link as its int8 values (four to a
+    32-bit word) and f32 block scales, never at full precision: a save
+    fetches its payload bytes."""
+    tree = _moments_tree()
+    int8 = {n: l for n, l in ser.tree_paths(tree)
+            if ser.default_quant_policy(n, l)}
+    assert sorted(int8) == ["opt_state/m", "opt_state/v"]
+    fetched = []
+    device_get = jax.device_get
+
+    def spy(x):
+        out = device_get(x)
+        fetched.extend(np.asarray(a) for a in jax.tree.leaves(out))
+        return out
+
+    monkeypatch.setattr(jax, "device_get", spy)
+    _, manifest = ser.serialize_tree(tree, ser.default_quant_policy)
+    blocks = {-(-l.size // ser.QUANT_BLOCK) for l in int8.values()}
+    for a in fetched:
+        assert not any(a.shape == l.shape and a.dtype == l.dtype
+                       for l in int8.values()), a.shape
+        if a.dtype == np.float32:
+            assert a.ndim == 1 and a.size in blocks, a.shape
+    values = [a for a in fetched if a.dtype == np.uint32]
+    assert sorted(a.shape for a in values) == sorted(
+        (b, ser.QUANT_BLOCK // 4) for b in blocks)
+    assert sum(a.nbytes for a in fetched) == manifest["total_bytes"]
+
+
+@pytest.mark.parametrize("shape", [(64, 32), (3, 1000)])
+def test_quantized_payload_is_the_reference_on_the_host_copy(shape):
+    """The payload is, byte for byte, the reference quantization of the
+    zero-padded f32 host copy: values, then scales."""
+    leaf = jnp.asarray(np.random.default_rng(5).normal(0, 0.02, shape),
+                       jnp.float32)
+    host = np.asarray(leaf, np.float32).reshape(-1)
+    pad = (-host.size) % ser.QUANT_BLOCK
+    q, s = ref.quantize_blockwise(jnp.asarray(np.pad(host, (0, pad))),
+                                  ser.QUANT_BLOCK)
+    payload, meta = ser.serialize_leaf(leaf, True, "opt_state/m")
+    assert payload == np.asarray(q).tobytes() + \
+        np.asarray(s, np.float32).tobytes()
+    assert meta == {"shape": list(shape), "dtype": "float32", "quant": True,
+                    "pad": pad, "nq": host.size + pad,
+                    "block": ser.QUANT_BLOCK}
+    out = ser.deserialize_leaf(payload, meta)
+    assert out.shape == shape and out.dtype == np.float32
+    # blockwise int8: |err| <= max|block| / 254 + eps
+    assert np.abs(out - np.asarray(leaf)).max() <= \
+        np.abs(host).max() / 127 + 1e-6
+
+
+@pytest.mark.slow
+def test_sharded_moment_serializes_as_its_host_copy_subprocess():
+    """A moment sharded over four devices is quantized on one of them: its
+    payload is the one its host copy gives."""
+    code = """
+        import jax, jax.numpy as jnp, numpy as np
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+        from repro.checkpoint import serializer as ser
+        mesh = Mesh(np.array(jax.devices()).reshape(2, 2), ("d", "m"))
+        host = np.random.default_rng(6).normal(0, 0.02, (48, 1000))
+        host = host.astype(np.float32)
+        leaf = jax.device_put(host, NamedSharding(mesh, P("d", "m")))
+        assert len(leaf.sharding.device_set) == 4
+        assert ser.serialize_leaf(leaf, True) == \
+            ser.serialize_leaf(host, True)
+        print("SHARDED-OK")
+    """
+    env = dict(os.environ, PYTHONPATH=SRC, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    out = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
+                         capture_output=True, text=True, env=env, timeout=300)
+    assert out.returncode == 0, out.stderr[-3000:]
+    assert "SHARDED-OK" in out.stdout
 
 
 def test_manager_save_restore_roundtrip():

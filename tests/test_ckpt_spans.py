@@ -1,5 +1,6 @@
-"""The checkpoint layer's spans: a save is ``ckpt.serialize`` (each leaf's
-``ckpt.fetch`` and ``ckpt.quantize``) and ``ckpt.save`` (``ckpt.pwrite``,
+"""The checkpoint layer's spans: a save is ``ckpt.serialize`` (each int8
+leaf's ``ckpt.quantize`` on the device, then each leaf's ``ckpt.fetch`` of
+its payload) and ``ckpt.save`` (``ckpt.pwrite``,
 ``ckpt.barrier``); a restore is ``ckpt.restore`` (``ckpt.stage``,
 ``ckpt.read``, each leaf's ``ckpt.dequantize`` and ``ckpt.place``); compiles
 inside them are ``jax.compile``; the flush says whether it was acked.
@@ -80,6 +81,13 @@ def test_save_and_restore_split_into_leaf_spans(system):
     fetched = {e[7]["leaf"]: e[7]["bytes"] for e in evs
                if e[3] == "ckpt.fetch"}
     assert fetched["params/w"] == 64 * 48 * 2
+    # an int8 leaf fetches its payload, once quantized on the device
+    leaf = tree["opt_state"]["m"]
+    assert fetched["opt_state/m"] == ser.leaf_nbytes(leaf, True)
+    (quant,) = [e for e in evs if e[3] == "ckpt.quantize"]
+    (fetch,) = [e for e in evs if e[3] == "ckpt.fetch"
+                and e[7]["leaf"] == "opt_state/m"]
+    assert quant[5] + quant[6] <= fetch[5]
     # every checkpoint span carries the step; the roots stay roots
     by_id = {e[1]: e for e in evs}
     roots = sorted(e[3] for e in evs if e[2] == 0)

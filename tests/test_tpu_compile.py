@@ -87,6 +87,21 @@ def test_quantize_compiles_for_ragged_block_count(one_chip):
     _compile(quantize_blockwise_pallas, x)
 
 
+# deepseek-llm-7b.2l moments: embedding, stacked MLP matrix, stacked norms
+SAVE_LEAVES = [(25600, 4096), (2, 4096, 11008), (2, 4096)]
+
+
+@pytest.mark.parametrize("shape", SAVE_LEAVES)
+def test_leaf_quantize_compiles_for_v5e(one_chip, shape, monkeypatch):
+    """A save's flatten, pad and kernel on the device, one program a leaf
+    shape (shapes no CPU test traces: the jitted trace is kept by shape)."""
+    from repro.checkpoint import serializer as ser
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    x = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+    hlo = ser._quantize_leaf.lower(x, block=ser.QUANT_BLOCK).compile()
+    assert "tpu_custom_call" in hlo.as_text()
+
+
 def _attn_args(one_chip, seq=4096):
     q = jax.ShapeDtypeStruct((1, seq, 24, 128), jnp.bfloat16,
                              sharding=one_chip)
