@@ -80,11 +80,12 @@ def int8_bound_readings(tree, seed):
 
 def calibrate(cfg, mix, seeds, controls, log=print):
     import jax
-    from bench import harness, reference
+    from bench import harness
     from repro.launch import train
     if jax.devices()[0].platform != "tpu":
         raise harness.NoChip("calibration reads the chip")
     program = harness.Program(cfg, harness.Hooks())
+    reference = harness.reference_for(cfg)
     low = LOWER[cfg["training"]["compute_dtype"]]
     rows = []
     for i, seed in enumerate(seeds):

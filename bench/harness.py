@@ -9,6 +9,31 @@ or cell's limits sits in a file of its own, found by the name that
     bench/metrics/<metric>.py     a reader: ``read(ctx) -> float | None``
     bench/limits/<workload>.json  the limit of every number compared
 
+A configuration file also brings what belongs to its model:
+
+    "reference": "<path>.py"      its plain reference, a module loaded by
+                                  path (``reference_for``); the path is
+                                  resolved against the checkout that holds
+                                  the cell's BENCHMARK.json, then against
+                                  this repository
+    "program": {<field>: value}   ``ModelConfig`` fields of the program,
+                                  set after the mapping every file shares
+                                  (``program_config``); lists become tuples
+
+A reference module owns the layout of the parameter tree and answers:
+
+    param_shapes(cfg) -> {leaf name: shape}   the layout; ``bench/weights.py``
+                                  draws the seed's tree from it, and the
+                                  program's tree has to match it
+    train(cfg, seed, batches, *, steps, low=None, half=False,
+          extra_loss_batch=None, extra_loss_after=None) -> dict
+                                  the reference's steps: ``losses``,
+                                  ``grad_norms``, ``change_norms``,
+                                  ``extra_loss`` (see ``bench/reference.py``)
+    lr_at(tcfg, step)             the learning rate of the file's schedule
+    grads_fn(cfg)                 one step's jitted loss and gradients,
+                                  for lowering ahead of time
+
 The program is driven through its own API (``repro.launch.train``'s
 ``build``, ``state_struct``, ``ckpt_tree``, ``bb_config_for`` and
 ``restore_state``; ``BBCheckpointManager`` on a running
@@ -41,7 +66,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench import data, peaks, reference, trace, weights
+from bench import data, peaks, trace, weights
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH = os.path.join(ROOT, "bench")
@@ -81,7 +106,8 @@ def load_cell(name: str, bench_dir: str = BENCH,
         raise KeyError(f"no workload {name!r} in BENCHMARK.json")
     w = cells[name]
     conf = {c["name"]: c for c in spec["configs"]}[w["config"]]
-    cfg = _json(os.path.join(os.path.dirname(bench_dir), conf["file"]))
+    root = os.path.dirname(bench_dir)
+    cfg = config_file(os.path.join(root, conf["file"]), root)
     mix = _json(os.path.join(bench_dir, "mixes", w["traffic"] + ".json"))
     limits = _json(os.path.join(bench_dir, "limits", name + ".json"))
 
@@ -96,32 +122,72 @@ def load_cell(name: str, bench_dir: str = BENCH,
 
 
 def load_config(name: str, bench_dir: str = BENCH) -> dict:
-    return _json(os.path.join(bench_dir, "configs", name + ".json"))
+    return config_file(os.path.join(bench_dir, "configs", name + ".json"),
+                       os.path.dirname(bench_dir))
 
 
-def metric_module(name: str, bench_dir: str = BENCH):
-    """The reader file of a per-layer metric, ``metrics/<name>.py``."""
-    path = os.path.join(bench_dir, "metrics", name + ".py")
-    spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_"), path)
+def config_file(path: str, root: str = ROOT) -> dict:
+    """A configuration file, its ``reference`` made an absolute path: the
+    file under the checkout ``root`` where it is there, else this
+    repository's."""
+    cfg = _json(path)
+    for base in (root, ROOT):
+        ref = os.path.join(base, cfg["reference"])
+        if os.path.isfile(ref):
+            cfg["reference"] = os.path.realpath(ref)
+            return cfg
+    raise FileNotFoundError(f"{path}: its reference {cfg['reference']!r} is "
+                            f"neither under {root} nor under {ROOT}")
+
+
+def _module(modname: str, path: str):
+    spec = importlib.util.spec_from_file_location(modname, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
 
 
+def metric_module(name: str, bench_dir: str = BENCH):
+    """The reader file of a per-layer metric, ``metrics/<name>.py``."""
+    return _module("bench_metric_" + name.replace(".", "_"),
+                   os.path.join(bench_dir, "metrics", name + ".py"))
+
+
+_REFERENCES: dict = {}
+
+
+def reference_for(cfg: dict):
+    """The plain reference module that the configuration names (its path as
+    ``config_file`` resolved it), loaded once per path."""
+    path = cfg["reference"]
+    if path not in _REFERENCES:
+        name = os.path.splitext(os.path.basename(path))[0]
+        _REFERENCES[path] = _module("bench_reference_" + name, path)
+    return _REFERENCES[path]
+
+
 # ------------------------------------------------------------ the program
 
 
+def _tuples(v):
+    return tuple(map(_tuples, v)) if isinstance(v, list) else v
+
+
 def program_config(cfg: dict):
-    """The program's ModelConfig for a configuration file; raises where the
-    program cannot run what the file states."""
+    """The program's ModelConfig for a configuration file: the mapping every
+    file shares, then the fields of the file's ``program``. Raises where
+    the program cannot run what the file states."""
     from repro.configs.base import get_config
     base = get_config(cfg["program_arch"])
     acts = {"gelu_pytorch_tanh": "gelu", "silu": "silu"}
     t = cfg["training"]
-    if cfg["norm_epsilon"] != 1e-6:
-        raise ValueError("the program's norms use epsilon 1e-6")
-    return dataclasses.replace(
+    program = cfg.get("program", {})
+    unknown = sorted(set(program) - {f.name for f in dataclasses.fields(base)})
+    if unknown:
+        raise ValueError(f"{cfg['name']}: the configuration file's "
+                         f"\"program\" names {unknown}, which the "
+                         f"program's ModelConfig has no field for")
+    shared = dataclasses.replace(
         base, d_model=cfg["hidden_size"],
         num_heads=cfg["num_attention_heads"],
         num_kv_heads=cfg["num_key_value_heads"],
@@ -133,11 +199,21 @@ def program_config(cfg: dict):
         tie_embeddings=cfg["tie_word_embeddings"],
         param_dtype=t["param_dtype"], compute_dtype=t["compute_dtype"],
         optimizer=t["optimizer"])
+    mc = dataclasses.replace(shared, **{k: _tuples(v)
+                                        for k, v in program.items()})
+    # the program's norms fix epsilon at 1e-6 while its ModelConfig has no
+    # field for it
+    eps = getattr(mc, "norm_eps", 1e-6)
+    if cfg["norm_epsilon"] != eps:
+        raise ValueError(f"the program's norms use epsilon {eps}")
+    return mc
 
 
 def check_program_matches(cfg: dict, struct, optimizer):
-    """The program's state layout and optimizer are what the file states."""
-    want = weights.param_shapes(cfg)
+    """The program's state layout and optimizer are what the file states:
+    the layout that the configuration's reference gives."""
+    reference = reference_for(cfg)
+    want = reference.param_shapes(cfg)
     got = {n: (tuple(l.shape), str(l.dtype))
            for n, l in weights.flatten(struct.params).items()}
     dt = cfg["training"]["param_dtype"]
@@ -360,7 +436,9 @@ class Program:
         self.step = hooks.wrap_step(step) if hooks.wrap_step else step
         t = cfg["training"]
         self._grad_norms = _opt_grad_norms(t["optimizer"], t.get("b1", 0.0))
-        self._init = jax.jit(lambda key: weights.make_params(cfg, key))
+        shapes = reference_for(cfg).param_shapes(cfg)
+        self._init = jax.jit(lambda key: weights.make_params(cfg, shapes,
+                                                             key))
         self._opt_init = jax.jit(self.optimizer.init)
 
         # the start is made apart and handed in: made inside the same
@@ -596,8 +674,9 @@ def run(cell: Cell, seed: int, seconds: float, traced: bool, t_start: float,
         f"after the window")
     batches = [feed.host(i) for i in range(REF_STEPS)]
     extra = feed.at(REF_STEPS) if prog["resumed"] else None
-    ref = reference.train(cfg, seed, batches, steps=REF_STEPS,
-                          extra_loss_batch=extra, extra_loss_after=REF_STEPS)
+    ref = reference_for(cfg).train(cfg, seed, batches, steps=REF_STEPS,
+                                   extra_loss_batch=extra,
+                                   extra_loss_after=REF_STEPS)
     numbers.update(training_numbers(prog, ref))
     log(f"bench: reference done {time.perf_counter() - t_win1:.2f} s after "
         f"the window")

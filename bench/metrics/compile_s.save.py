@@ -1,6 +1,7 @@
 """Seconds per save spent in backend compiles: the program's
 ``jax.compile`` spans recorded inside a save's spans (mostly
-``ckpt.quantize``, whose eager kernel calls compile anew)."""
+``ckpt.quantize``, whose jitted programs, one per int8 leaf shape, compile
+or load from the persistent cache the first time the process meets them)."""
 from bench import spans
 
 

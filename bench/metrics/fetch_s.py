@@ -1,6 +1,8 @@
-"""Seconds per save of fetching the state to the host: the program's
-``ckpt.fetch`` spans, one per leaf (its blocking ``device_get`` of the
-full-precision value, and the copy to bytes of a leaf saved exactly)."""
+"""Seconds per save of fetching the checkpoint to the host: the program's
+``ckpt.fetch`` spans, one per leaf (every device-to-host copy of the leaf's
+payload and its copy into bytes: a leaf saved exactly is fetched whole, an
+int8 leaf as its values, bitcast on the device to 32-bit words, and its
+block scales)."""
 from bench import spans
 
 

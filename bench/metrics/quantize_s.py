@@ -1,7 +1,8 @@
-"""Seconds per save of quantizing the int8 leaves: the program's
-``ckpt.quantize`` spans, one per int8 leaf (the fetched value uploaded
-again, flattened and padded, the kernel, the fetch of its values and
-scales and their copy to bytes), compiles included."""
+"""Seconds per save of quantizing the int8 leaves where the state lies: the
+program's ``ckpt.quantize`` spans, one per int8 leaf (the jitted flatten,
+pad and kernel of the leaf on the device, and the bitcast of its values to
+32-bit words, until the results are ready; nothing crosses the host link
+inside it), compiles included."""
 from bench import spans
 
 

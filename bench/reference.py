@@ -1,9 +1,14 @@
-"""Plain reference of a decoder-only language model's training steps.
+"""Plain reference of a dense decoder-only language model's training steps.
 
 Straightforward ``jax.numpy`` in float32 at ``highest`` matmul precision,
 written from the configuration file alone: it imports nothing of the program
-under test and takes nothing that the program made. Weights come from
-``bench.weights`` with the run's seed, and batches from ``bench.data``.
+under test and takes nothing that the program made. It owns the layout of
+the parameter tree (``param_shapes``): the layers stacked along a leading
+axis, as the program keeps them. Weights come from ``bench.weights`` with the
+run's seed, and batches from ``bench.data``. A configuration file names its
+reference (``"reference"``); the harness loads it by that path
+(``bench.harness.reference_for``), so another layout brings a reference of
+its own with ``param_shapes``, ``train``, ``lr_at`` and ``grads_fn``.
 
 What the configuration states is kept: parameters are stored in their dtype
 (bfloat16) and rounded to it after every update; the optimizer's moments are
@@ -18,7 +23,9 @@ float32 product.
 from __future__ import annotations
 
 import functools
+import json
 import math
+from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -28,6 +35,51 @@ from bench import weights
 
 F32 = jnp.float32
 Q_BLOCK = 512
+
+Shapes = Dict[str, Tuple[int, ...]]
+
+
+def padded_vocab(vocab: int) -> int:
+    """Rows of the embedding: the vocabulary padded to a multiple of 256."""
+    return -(-vocab // 256) * 256
+
+
+def dims(cfg: dict) -> dict:
+    """The sizes that the layout needs, from a configuration file."""
+    d = cfg["hidden_size"]
+    h = cfg["num_attention_heads"]
+    return {"d": d, "h": h, "kv": cfg["num_key_value_heads"],
+            "hd": cfg.get("head_dim", d // h), "f": cfg["intermediate_size"],
+            "v": padded_vocab(cfg["vocab_size"]),
+            "layers": cfg["num_hidden_layers"],
+            "layernorm": cfg["norm"] == "layernorm",
+            "gated": cfg["mlp_gated"], "tied": cfg["tie_word_embeddings"]}
+
+
+def param_shapes(cfg: dict) -> Shapes:
+    """Leaf name ('/'-joined tree path) -> shape."""
+    z = dims(cfg)
+    d, h, kv, hd, f, v, n = (z[k] for k in ("d", "h", "kv", "hd", "f", "v",
+                                             "layers"))
+    out: Shapes = {"embed/tokens": (v, d)}
+    if not z["tied"]:
+        out["embed/unembed"] = (d, v)
+    norms = ("scale", "bias") if z["layernorm"] else ("scale",)
+    for k in norms:
+        out[f"final_norm/{k}"] = (d,)
+    layer = "segments/seg0/0"
+    for nm in ("norm1", "norm2"):
+        for k in norms:
+            out[f"{layer}/{nm}/{k}"] = (n, d)
+    out[f"{layer}/attn/wq"] = (n, d, h, hd)
+    out[f"{layer}/attn/wk"] = (n, d, kv, hd)
+    out[f"{layer}/attn/wv"] = (n, d, kv, hd)
+    out[f"{layer}/attn/wo"] = (n, h, hd, d)
+    out[f"{layer}/mlp/w_up"] = (n, d, f)
+    out[f"{layer}/mlp/w_down"] = (n, f, d)
+    if z["gated"]:
+        out[f"{layer}/mlp/w_gate"] = (n, d, f)
+    return out
 
 
 def _quantize(x, low):
@@ -291,10 +343,19 @@ _CFGS: dict = {}
 
 
 def _key(cfg: dict) -> str:
-    import json
     k = json.dumps(cfg, sort_keys=True)
     _CFGS[k] = cfg
     return k
+
+
+def grads_fn(cfg: dict):
+    """``f(params, batch) -> (loss, clipped gradients)`` of one reference
+    step, jitted, for lowering ahead of time (``bench/rehearse_compile.py``);
+    trace it under ``jax.default_matmul_precision("highest")`` as ``train``
+    runs it."""
+    key = _key(cfg)
+    return jax.jit(lambda params, batch: _grads(key, params, batch, None,
+                                                False))
 
 
 def train(cfg: dict, seed: int, batches, *, steps: int, low=None,
@@ -307,7 +368,7 @@ def train(cfg: dict, seed: int, batches, *, steps: int, low=None,
     ``extra_loss_after`` steps on that batch (forward only)."""
     key = _key(cfg)
     with jax.default_matmul_precision("highest"):
-        params = weights.init_params(cfg, seed)
+        params = weights.init_params(cfg, param_shapes(cfg), seed)
         opt = opt_init(cfg["training"], weights.flatten(params))
         losses, grad_norms, extra = [], None, None
         for i in range(steps):
@@ -323,7 +384,7 @@ def train(cfg: dict, seed: int, batches, *, steps: int, low=None,
         if extra_loss_batch is not None and extra_loss_after == steps:
             extra = float(_loss_only(key, params, extra_loss_batch, low))
         del opt
-        start = weights.init_params(cfg, seed)
+        start = weights.init_params(cfg, param_shapes(cfg), seed)
         change = {n: float(v) for n, v in
                   _change_norms(params, start).items()}
     return {"losses": losses, "grad_norms": grad_norms,

@@ -39,8 +39,7 @@ def main(argv=None):
     # the kernels pick their TPU path from the default backend, which here
     # is the CPU: steer them to the path the chip runs
     ops._on_tpu = lambda: True
-    with open(args.config) as f:
-        cfg = json.load(f)
+    cfg = harness.config_file(args.config)
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
     chip = SingleDeviceSharding(topo.devices[0])
@@ -54,14 +53,15 @@ def main(argv=None):
              for k in ("inputs", "labels")}
     t0 = time.perf_counter()
     if args.reference:
-        from bench import reference, weights
+        from bench import weights
+        reference = harness.reference_for(cfg)
         params = jax.tree.map(
             lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=chip),
             jax.eval_shape(lambda: weights.make_params(
-                cfg, jax.random.PRNGKey(0))))
+                cfg, reference.param_shapes(cfg), jax.random.PRNGKey(0))))
+        grads = reference.grads_fn(cfg)
         with jax.default_matmul_precision("highest"):
-            compiled = reference._grads.lower(
-                reference._key(cfg), params, batch, None, False).compile()
+            compiled = grads.lower(params, batch).compile()
     else:
         compiled = step_fn.lower(state, batch).compile()
     mem = compiled.memory_analysis()

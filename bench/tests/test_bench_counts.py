@@ -4,7 +4,7 @@ import pytest
 
 from bench import harness, weights
 from bench.harness import load_config as full_config
-from bench.harness import metric_module
+from bench.harness import metric_module, reference_for
 
 
 def test_sc2_step_flops_match_hand_sum():
@@ -54,13 +54,13 @@ def test_configs_are_the_programs(name, changed):
     program = harness.Program(cfg, harness.Hooks())
     got = {n: l.shape for n, l in
            weights.flatten(program.struct.params).items()}
-    assert got == weights.param_shapes(cfg)
+    assert got == reference_for(cfg).param_shapes(cfg)
 
 
 def test_deepseek_params_match_hand_sum():
     cfg = full_config("deepseek-coder-33b.2l")
     n = sum(int(jax.numpy.prod(jax.numpy.array(s)))
-            for s in weights.param_shapes(cfg).values())
+            for s in reference_for(cfg).param_shapes(cfg).values())
     per_layer = 2 * 7168 * 7168 + 2 * 7168 * 1024 + 3 * 7168 * 19200 \
         + 2 * 7168
     assert n == 2 * per_layer + 2 * 32256 * 7168 + 7168
@@ -70,7 +70,7 @@ def test_deepseek_params_match_hand_sum():
 def test_deepseek_llm_params_match_hand_sum():
     cfg = full_config("deepseek-llm-7b.2l")
     n = sum(int(jax.numpy.prod(jax.numpy.array(s)))
-            for s in weights.param_shapes(cfg).values())
+            for s in reference_for(cfg).param_shapes(cfg).values())
     per_layer = 4 * 4096 * 4096 + 3 * 4096 * 11008 + 2 * 4096
     assert n == 2 * per_layer + 2 * 25600 * 4096 + 4096
     assert n == 614_486_016

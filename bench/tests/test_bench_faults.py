@@ -7,7 +7,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from bench import calibrate, harness, reference
+from bench import calibrate, harness
 from bench_tiny import tiny_cell
 
 
@@ -68,6 +68,7 @@ def test_control_fails_the_limits(cell):
     cfg, seed = c.config, 7
     feed = harness.Feed(seed, cfg, c.mix)
     batches = [feed.host(i) for i in range(harness.REF_STEPS)]
+    reference = harness.reference_for(cfg)
     ref = reference.train(cfg, seed, batches, steps=harness.REF_STEPS)
     low = calibrate.LOWER[cfg["training"]["compute_dtype"]]
     ctl = reference.train(cfg, seed, batches, steps=harness.REF_STEPS,

@@ -1,64 +1,20 @@
 """The benchmark's weights: a parameter tree made on the device from the seed.
 
-The layout is that of a decoder-only language model whose layers are stacked
-along a leading axis, as the program under test keeps them. The harness
-hands the tree to the program, and the plain reference makes the same tree
-again from the same seed: neither takes weights from the other. Matrices
-are drawn from normal(0, initializer_range), the published configuration's
-own initializer; norm scales are one and norm biases zero.
+The layout, leaf name ('/'-joined tree path) -> shape, is the caller's:
+the ``param_shapes(cfg)`` of the plain reference that the configuration
+names. This module holds only what every layout shares. The harness hands
+the tree to the program, and the reference makes the same tree again from
+the same seed: neither takes weights from the other. Leaves are drawn in
+the sorted order of their names, each from its own split of the seed's
+key: matrices from normal(0, initializer_range), the published
+configuration's own initializer; norm scales are one and norm biases zero.
 """
 from __future__ import annotations
 
 import hashlib
-from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
-
-Shapes = Dict[str, Tuple[int, ...]]
-
-
-def padded_vocab(vocab: int) -> int:
-    """Rows of the embedding: the vocabulary padded to a multiple of 256."""
-    return -(-vocab // 256) * 256
-
-
-def dims(cfg: dict) -> dict:
-    """The sizes that the layout needs, from a configuration file."""
-    d = cfg["hidden_size"]
-    h = cfg["num_attention_heads"]
-    return {"d": d, "h": h, "kv": cfg["num_key_value_heads"],
-            "hd": cfg.get("head_dim", d // h), "f": cfg["intermediate_size"],
-            "v": padded_vocab(cfg["vocab_size"]),
-            "layers": cfg["num_hidden_layers"],
-            "layernorm": cfg["norm"] == "layernorm",
-            "gated": cfg["mlp_gated"], "tied": cfg["tie_word_embeddings"]}
-
-
-def param_shapes(cfg: dict) -> Shapes:
-    """Leaf name ('/'-joined tree path) -> shape."""
-    z = dims(cfg)
-    d, h, kv, hd, f, v, n = (z[k] for k in ("d", "h", "kv", "hd", "f", "v",
-                                             "layers"))
-    out: Shapes = {"embed/tokens": (v, d)}
-    if not z["tied"]:
-        out["embed/unembed"] = (d, v)
-    norms = ("scale", "bias") if z["layernorm"] else ("scale",)
-    for k in norms:
-        out[f"final_norm/{k}"] = (d,)
-    layer = "segments/seg0/0"
-    for nm in ("norm1", "norm2"):
-        for k in norms:
-            out[f"{layer}/{nm}/{k}"] = (n, d)
-    out[f"{layer}/attn/wq"] = (n, d, h, hd)
-    out[f"{layer}/attn/wk"] = (n, d, kv, hd)
-    out[f"{layer}/attn/wv"] = (n, d, kv, hd)
-    out[f"{layer}/attn/wo"] = (n, h, hd, d)
-    out[f"{layer}/mlp/w_up"] = (n, d, f)
-    out[f"{layer}/mlp/w_down"] = (n, f, d)
-    if z["gated"]:
-        out[f"{layer}/mlp/w_gate"] = (n, d, f)
-    return out
 
 
 def nest(flat: dict) -> dict:
@@ -100,10 +56,9 @@ def _leaf(name: str, shape, key, std: float, dtype):
     return (jax.random.normal(key, shape, jnp.float32) * std).astype(dtype)
 
 
-def make_params(cfg: dict, key):
-    """The whole tree from ``key``, traceable, in the configuration's
-    parameter dtype."""
-    shapes = param_shapes(cfg)
+def make_params(cfg: dict, shapes: dict, key):
+    """The tree of the layout ``shapes`` from ``key``, traceable, in the
+    configuration's parameter dtype."""
     dtype = jnp.dtype(cfg["training"]["param_dtype"])
     std = float(cfg["initializer_range"])
     keys = jax.random.split(key, len(shapes))
@@ -111,7 +66,8 @@ def make_params(cfg: dict, key):
                  for (name, shape), k in zip(sorted(shapes.items()), keys)})
 
 
-def init_params(cfg: dict, seed: int):
-    """The seed's tree, made on the default device in one jitted call."""
-    return jax.jit(lambda key: make_params(cfg, key))(
+def init_params(cfg: dict, shapes: dict, seed: int):
+    """The seed's tree of the layout ``shapes``, made on the default device
+    in one jitted call."""
+    return jax.jit(lambda key: make_params(cfg, shapes, key))(
         seed_key(seed, "weights"))
